@@ -1,8 +1,9 @@
 """Kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels execute in interpret mode, so
-wall-clock numbers characterize the *reference* path only; the structural
-numbers (FLOPs, VMEM working set) are the TPU-relevant derived columns.
+On the CPU the Pallas kernels execute in interpret mode
+(``repro.kernels.interpret_mode``), so wall-clock numbers there
+characterize the interpreter only; the structural numbers (FLOPs, VMEM
+working set) are the TPU-relevant derived columns.
 """
 from __future__ import annotations
 
@@ -10,6 +11,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import interpret_mode
 
 
 def _time(fn, *args, iters=3):
@@ -28,8 +31,8 @@ def bench_flash_attention():
     q = jax.random.normal(key, (b, s, h, d), jnp.float32)
     k = jax.random.normal(key, (b, s, kvh, d), jnp.float32)
     v = jax.random.normal(key, (b, s, kvh, d), jnp.float32)
-    us = _time(lambda *a: mha(*a, causal=True, interpret=True, bq=128,
-                              bk=128), q, k, v)
+    us = _time(lambda *a: mha(*a, causal=True, interpret=interpret_mode(),
+                              bq=128, bk=128), q, k, v)
     flops = 4 * b * h * s * s * d / 2
     vmem_kib = (128 * d * 4 * 3 + 128 * 128 * 4) / 1024
     return [{"kernel": "flash_attention", "us_per_call": us,
@@ -44,7 +47,7 @@ def bench_decode_attention():
     k = jax.random.normal(key, (b, s, kvh, d), jnp.float32)
     v = jax.random.normal(key, (b, s, kvh, d), jnp.float32)
     us = _time(lambda *a: gqa_decode(*a, jnp.int32(s), bk=512,
-                                     interpret=True), q, k, v)
+                                     interpret=interpret_mode()), q, k, v)
     bytes_hbm = 2 * b * s * kvh * d * 4
     return [{"kernel": "decode_attention", "us_per_call": us,
              "cache_bytes": bytes_hbm,
@@ -60,7 +63,8 @@ def bench_ssd_scan():
     a = -jnp.ones((h,))
     bm = jax.random.normal(key, (b, s, n))
     cm = jax.random.normal(key, (b, s, n))
-    us = _time(lambda *args: ssd_scan(*args, chunk=128, interpret=True),
+    us = _time(lambda *args: ssd_scan(*args, chunk=128,
+                                      interpret=interpret_mode()),
                x, dt, a, bm, cm)
     chunk_flops = 2 * 128 * 128 * (n + p)
     return [{"kernel": "ssd_scan", "us_per_call": us,
@@ -74,7 +78,7 @@ def bench_moe_gmm():
     e, c, k, f = 8, 256, 256, 512
     x = jax.random.normal(key, (e, c, k))
     w = jax.random.normal(key, (e, k, f))
-    us = _time(lambda *a: gmm(*a, interpret=True), x, w)
+    us = _time(lambda *a: gmm(*a, interpret=interpret_mode()), x, w)
     return [{"kernel": "moe_gmm", "us_per_call": us,
              "flops": 2 * e * c * k * f,
              "mxu_tile": "128x128x128"}]

@@ -1,10 +1,14 @@
 """Pallas TPU kernels (kernel.py + ops.py wrapper + ref.py oracle each).
 
 ``enable_kernels(True)`` routes the model stack's hot paths through the
-kernels (interpret mode on CPU — used by the integration tests; compiled
-on real TPUs). Default off: the pure-jnp reference path is the oracle
-and the dry-run path (Pallas cannot lower on the CPU dry-run backend).
+kernels. Default off: the pure-jnp path is the oracle. Kernel entry
+points compile by default (``interpret=False``); callers that run on
+whatever backend is present pass ``interpret=interpret_mode()``, so the
+Pallas interpreter runs only on the CPU and a kernel on the chip either
+compiles or fails loudly.
 """
+import jax
+
 _ENABLED = False
 
 
@@ -15,3 +19,9 @@ def enable_kernels(on: bool = True):
 
 def kernels_enabled() -> bool:
     return _ENABLED
+
+
+def interpret_mode() -> bool:
+    """True only where the default backend is the CPU: Pallas has no CPU
+    compiler, so kernels run in its interpreter there and nowhere else."""
+    return jax.default_backend() == "cpu"

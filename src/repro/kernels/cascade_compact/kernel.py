@@ -1,24 +1,23 @@
 """Pallas TPU kernel: pending-set compaction for the cascade executor.
 
-Block-sequential formulation: the index vector is processed in blocks of
-``block`` rows (grid dim, sequential), with the running survivor count
-carried in SMEM scratch — so memory is O(n + block^2), not O(n^2), and
-a 100k-row pending set never materializes a 100k x 100k select matrix.
-Per block the step is TPU-friendly prefix-sum + gather:
+Output-blocked formulation. The wrapper computes every kept row's
+destination slot once, ``pos = cumsum(keep) - 1`` (``-1`` for dropped
+rows), as a plain XLA prefix sum. The kernel then fills the output one
+block of ``block`` slots at a time (grid dim 0, parallel), scanning the
+input in blocks (grid dim 1, arbitrary) and accumulating
 
-  * ``pos = cumsum(keep) - 1`` assigns every kept row its slot within
-    the block;
-  * a 0/1 select matrix ``sel[i, k] = keep[i] & (pos[i] == k)`` turns
-    the block gather into a single MXU matmul — no scatter and no sort,
-    the two primitives TPU Pallas handles worst;
-  * the block's compacted rows are stored at the running base offset
-    (``pl.ds`` dynamic store). The padded tail of each store is garbage
-    that the NEXT block overwrites (the grid is sequential); whatever
-    garbage survives past the total count is masked with ``fill`` by
-    the wrapper.
+    out[k] = sum_i idx[i] * (pos[i] == k)
 
-Bit-exact against ``ref.compact_ref`` (int32 arithmetic throughout) —
-the equivalence suite (tests/test_placement.py) relies on that.
+as a compare, a select and a lane reduction on the VPU. Each slot
+receives at most one row, so the int32 sum is exact, and there is no
+dynamic store, scatter, sort or integer matmul: the shapes Mosaic (the
+TPU kernel compiler) handles worst. A kept row never moves right
+(``pos[i] <= i``), so input blocks left of the output block are skipped.
+Memory is O(block^2) per step; work is O(n^2 / 2) compares, which is
+microseconds at serving batch sizes.
+
+Bit-exact against ``ref.compact_ref`` — the equivalence suite
+(tests/test_placement.py) relies on that.
 """
 from __future__ import annotations
 
@@ -30,57 +29,50 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compact_kernel(idx_ref, keep_ref, out_ref, base_ref, *, q: int):
-    bk = pl.program_id(0)
+def _compact_kernel(idx_ref, pos_ref, out_ref, *, q: int):
+    kb = pl.program_id(0)
+    ib = pl.program_id(1)
 
-    @pl.when(bk == 0)
+    @pl.when(ib == 0)
     def _init():
-        base_ref[0] = 0
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    idx = idx_ref[...]                               # (1, q) int32 block
-    keep = keep_ref[...] != 0                        # (1, q)
-    ki = keep.astype(jnp.int32)
-    pos = jnp.cumsum(ki, axis=1) - 1                 # slot within block
-    local = jnp.sum(ki)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    sel = (keep[0][:, None] & (pos[0][:, None] == cols)).astype(jnp.int32)
-    gathered = jax.lax.dot_general(idx, sel, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.int32)
-    base = base_ref[0]
-    pl.store(out_ref, (slice(None), pl.ds(base, q)), gathered)
-    base_ref[0] = base + local
+    @pl.when(ib >= kb)                               # pos[i] <= i
+    def _gather():
+        slot = kb * q + jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+        hit = pos_ref[...] == slot                   # (q slots, q rows)
+        vals = jnp.where(hit, idx_ref[...], 0)
+        out_ref[...] += jnp.sum(vals, axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("fill", "interpret", "block"))
-def compact_pallas(idx, keep, *, fill: int = -1, interpret: bool = True,
+@functools.partial(jax.jit, static_argnames=("fill", "interpret", "block"))
+def compact_pallas(idx, keep, *, fill: int = -1, interpret: bool = False,
                    block: int = 256):
     """idx (n,) int32, keep (n,) bool -> (padded (n,) int32, count).
 
     ``padded[:count]`` are the kept indices in original order; the tail
-    is ``fill``. ``block`` is the per-grid-step row count (the select
-    matrix is block x block).
+    is ``fill``. ``block`` is the per-grid-step slot and row count (a
+    multiple of 128 on the chip, or at least ``n``).
     """
     n = idx.shape[0]
     q = min(block, max(n, 1))
     nb = -(-n // q)                                  # ceil blocks
     n_pad = nb * q
+    ki = keep.astype(jnp.int32)
+    pos = jnp.where(keep, jnp.cumsum(ki) - 1, -1)
     idx_p = jnp.pad(idx.astype(jnp.int32), (0, n_pad - n))
-    keep_p = jnp.pad(keep, (0, n_pad - n))           # pad rows: keep=False
-    kernel = functools.partial(_compact_kernel, q=q)
+    pos_p = jnp.pad(pos, (0, n_pad - n), constant_values=-1)
     out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, q), lambda b: (0, b)),
-                  pl.BlockSpec((1, q), lambda b: (0, b))],
-        # the output is revisited whole by every block: each stores its
-        # compacted rows at the running offset; one trailing block of
-        # slack keeps the fixed-width dynamic store in bounds
-        out_specs=pl.BlockSpec((1, n_pad + q), lambda b: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad + q), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        functools.partial(_compact_kernel, q=q),
+        grid=(nb, nb),
+        in_specs=[pl.BlockSpec((1, q), lambda k, i: (0, i)),
+                  pl.BlockSpec((1, q), lambda k, i: (0, i))],
+        out_specs=pl.BlockSpec((q, 1), lambda k, i: (k, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(idx_p[None, :], keep_p[None, :].astype(jnp.int32))
-    count = jnp.sum(keep.astype(jnp.int32))
+    )(idx_p[None, :], pos_p[None, :])
+    count = jnp.sum(ki)
     lane = jnp.arange(n, dtype=jnp.int32)
-    return jnp.where(lane < count, out[0, :n], fill), count
+    return jnp.where(lane < count, out[:n, 0], fill), count

@@ -7,8 +7,8 @@ oracle (``ref.compact_ref``):
     rows keep their original relative order; sort keys are distinct so
     the result is deterministic on every XLA backend);
   * ``backend="pallas"`` — the Pallas kernel (``kernel.compact_pallas``,
-    interpret mode on CPU, compiled on real TPUs) alongside the repo's
-    other kernel families.
+    interpret mode on the CPU, compiled everywhere else) alongside the
+    repo's other kernel families.
 
 Fixed output shape (padded to the input length, ``fill`` in the tail)
 keeps both variants jittable; the true length comes back as a scalar
@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.cascade_compact.kernel import compact_pallas
 
 BACKENDS = ("jnp", "pallas")
@@ -45,9 +46,9 @@ def compact(idx, keep, *, backend: str = "jnp", fill: int = -1,
             interpret: bool | None = None, block: int = 256):
     """idx (n,), keep (n,) bool -> (padded (n,) int32 device array,
     count int32 scalar). ``padded[:count]`` are the kept indices in
-    original order. ``interpret=None`` auto-selects: the Pallas
-    interpreter everywhere except a real TPU backend, where the kernel
-    compiles; ``block`` is the Pallas kernel's per-grid-step row count.
+    original order. ``interpret=None`` defers to
+    ``repro.kernels.interpret_mode`` (the interpreter on the CPU only);
+    ``block`` is the Pallas kernel's per-grid-step row count.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown compaction backend {backend!r}; "
@@ -61,7 +62,7 @@ def compact(idx, keep, *, backend: str = "jnp", fill: int = -1,
         return idx.astype(jnp.int32), jnp.int32(0)
     if backend == "pallas":
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         return compact_pallas(idx, keep, fill=fill, interpret=interpret,
                               block=block)
     return _compact_jnp(idx, keep, fill)

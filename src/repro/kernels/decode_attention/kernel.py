@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import SMEM, tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -56,7 +54,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention(q, k, v, length, *, bk: int = 512,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """q: (B, KVH, G, d); k/v: (B, S, KVH, d); length: scalar valid-length.
 
     Returns (B, KVH, G, dv)."""
@@ -74,7 +72,7 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
         kernel,
         grid=(b, kvh, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g, d), lambda bi, hi, kj: (bi, hi, 0, 0)),
             pl.BlockSpec((1, bk, 1, d), lambda bi, hi, kj: (bi, kj, hi, 0)),
             pl.BlockSpec((1, bk, 1, dv), lambda bi, hi, kj: (bi, kj, hi, 0)),
@@ -86,7 +84,7 @@ def decode_attention(q, k, v, length, *, bk: int = 512,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length_arr, q, k, v)

@@ -4,7 +4,7 @@ from __future__ import annotations
 from repro.kernels.decode_attention.kernel import decode_attention
 
 
-def gqa_decode(q, k, v, length, *, bk: int = 512, interpret: bool = True):
+def gqa_decode(q, k, v, length, *, bk: int = 512, interpret: bool = False):
     """q: (B, 1, H, d) single-token query; k/v: (B, S, KVH, d).
 
     Returns (B, 1, H, dv)."""

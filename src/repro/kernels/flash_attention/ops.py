@@ -7,7 +7,7 @@ from repro.kernels.flash_attention.kernel import flash_attention
 
 
 def mha(q, k, v, *, causal: bool = True, window: int = 0,
-        interpret: bool = True, bq: int = 128, bk: int = 128):
+        interpret: bool = False, bq: int = 128, bk: int = 128):
     """q: (B, S, H, d); k/v: (B, S, KVH, d). Returns (B, S, H, dv).
 
     KV heads are broadcast to query heads (GQA) before the kernel; the

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     kj = pl.program_id(3)
@@ -35,7 +33,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
 
 @functools.partial(jax.jit, static_argnames=("bc", "bf", "bk", "interpret"))
 def gmm(x, w, *, bc: int = 128, bf: int = 128, bk: int = 128,
-        interpret: bool = True):
+        interpret: bool = False):
     """x: (E, C, K); w: (E, K, F) -> (E, C, F)."""
     e, c, k = x.shape
     f = w.shape[-1]
@@ -54,7 +52,7 @@ def gmm(x, w, *, bc: int = 128, bf: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((1, bc, bf), lambda ei, ci, fi, kj: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

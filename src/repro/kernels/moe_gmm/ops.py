@@ -6,7 +6,7 @@ import jax
 from repro.kernels.moe_gmm.kernel import gmm
 
 
-def expert_mlp(x, w_gate, w_up, w_down, *, interpret: bool = True):
+def expert_mlp(x, w_gate, w_up, w_down, *, interpret: bool = False):
     """x: (E, C, d); w_*: (E, d, f)/(E, f, d). SwiGLU expert FFN."""
     g = gmm(x, w_gate, interpret=interpret)
     u = gmm(x, w_up, interpret=interpret)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                 q: int):
@@ -63,7 +61,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, interpret: bool = False):
     """x: (B, S, H, P); dt: (B, S, H); a: (H,); bm/cm: (B, S, N).
 
     Returns y: (B, S, H, P) = SSD(x*dt) without the D skip term."""
@@ -97,7 +95,7 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk: int = 256, interpret: bool = True):
                                lambda bi, hi, cj: (bi, hi, cj, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, nc, chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xr, dtr, a, br, cr)

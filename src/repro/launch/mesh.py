@@ -12,12 +12,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e pod: 16x16 = 256 chips; multi-pod: 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding policy pins
+    intermediates with ``with_sharding_constraint``, which accepts only
+    ``Auto`` axes (``make_mesh`` defaults to ``Explicit`` ones)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

@@ -21,7 +21,11 @@ Demo (CPU):
 
 Thin CLI over ``repro.serving.build_pipeline`` — this is the entry point
 a real deployment would point at the production mesh (tiers sharded with
-pjit per DESIGN.md §5; ``--mesh`` is that path on a forced-CPU grid).
+pjit per DESIGN.md §5). On an accelerator ``--devices``/``--mesh`` use
+the chips that are there and exit with an error when too few are;
+under ``JAX_PLATFORMS=cpu`` they force that many host devices. Compiled
+programs persist in JAX's compilation cache
+(``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -29,13 +33,14 @@ import argparse
 import os
 import sys
 
-# --devices N / --mesh R,C force an N- (R*C-) device host platform (CPU
-# dev boxes have one device; tier placement/sharding needs several). XLA
-# locks the device count at first use, so the flag must land in the
-# environment BEFORE anything imports jax — pre-parse it here, ahead of
-# the repro imports below. Both `--flag V` and `--flag=V` spellings
-# count; if the user already exported their own XLA_FLAGS we leave it
-# alone and main() warns when the resulting device count falls short.
+# On the CPU (JAX_PLATFORMS=cpu), --devices N / --mesh R,C force an N-
+# (R*C-) device host platform: a CPU box has one device, and tier
+# placement/sharding needs several. XLA locks the device count at first
+# use, so the flag must land in the environment BEFORE anything imports
+# jax — pre-parse it here, ahead of the repro imports below. Both
+# `--flag V` and `--flag=V` spellings count; a user's own XLA_FLAGS is
+# left alone, and main() exits with an error when the devices that
+# result fall short of the request.
 
 
 def _preparse(argv, flag: str) -> str | None:
@@ -85,17 +90,25 @@ def _parse_faults(spec: str, n_tiers: int):
     return broadcast if broadcast is not None else per_tier
 
 
-_n = _preparse(sys.argv, "--devices")
-_mesh = _parse_mesh(_preparse(sys.argv, "--mesh"))
-if _mesh is not None and (_n is None or not _n.isdigit()
-                          or int(_n) < _mesh[0] * _mesh[1]):
-    _n = str(_mesh[0] * _mesh[1])
-if (_n is not None and _n.isdigit() and int(_n) > 1
-        and "XLA_FLAGS" not in os.environ):
-    os.environ["XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={_n}"
+def _force_host_devices(argv, environ) -> None:
+    """Under ``JAX_PLATFORMS=cpu``, write the host device count that
+    ``--devices``/``--mesh`` ask for into ``environ["XLA_FLAGS"]``. Any
+    other platform keeps the devices it has."""
+    if environ.get("JAX_PLATFORMS") != "cpu" or "XLA_FLAGS" in environ:
+        return
+    n = _preparse(argv, "--devices")
+    mesh = _parse_mesh(_preparse(argv, "--mesh"))
+    if mesh is not None and (n is None or not n.isdigit()
+                             or int(n) < mesh[0] * mesh[1]):
+        n = str(mesh[0] * mesh[1])
+    if n is not None and n.isdigit() and int(n) > 1:
+        environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+
+
+_force_host_devices(sys.argv, os.environ)
 
 from repro.core.router import RouterConfig            # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.data import synthetic                      # noqa: E402
 from repro.serving import BuildConfig, build_pipeline  # noqa: E402
 from repro.serving.ingress import poisson_arrivals    # noqa: E402
@@ -192,17 +205,20 @@ def main():
     ap.add_argument("--devices", type=int, default=None,
                     help="pin each cascade tier's model to its own "
                          "device, sized by offline traffic share "
-                         "(forces an N-device CPU host when the "
-                         "platform has fewer; results are bit-identical "
-                         "to the shared device)")
+                         "(under JAX_PLATFORMS=cpu, forces an N-device "
+                         "host; elsewhere, exits when fewer exist; "
+                         "results are bit-identical to the shared "
+                         "device)")
     ap.add_argument("--mesh", default=None,
                     help="R,C: shard each cascade tier over its own "
                          "contiguous slice of an RxC device grid (rows "
                          "= data/FSDP axis, cols = tensor axis), sized "
-                         "by offline traffic share; forces an R*C-"
-                         "device CPU host when the platform has fewer. "
-                         "C=1 slices are bit-identical to the unsharded "
-                         "pipeline. Mutually exclusive with --devices")
+                         "by offline traffic share; under JAX_PLATFORMS="
+                         "cpu forces an R*C-device host, elsewhere exits "
+                         "when fewer exist. C=1 slices are bit-identical "
+                         "to the unsharded pipeline on CPU devices; on a "
+                         "TPU a greedy token may flip at a near-tie. "
+                         "Mutually exclusive with --devices")
     ap.add_argument("--speculate", action="store_true",
                     help="stream mode: speculative cascade execution — "
                          "idle tier workers pre-invoke predicted-reject "
@@ -277,12 +293,8 @@ def main():
         import jax
         avail = len(jax.local_devices())
         if avail < need:
-            # a pre-existing XLA_FLAGS wins over the pre-parse above
-            print(f"warning: {need} devices requested but only "
-                  f"{avail} available (XLA_FLAGS already set?); tiers "
-                  f"will share devices")
-            if mesh_shape:
-                mesh_shape = (avail, 1)
+            ap.error(f"{need} devices requested (--devices/--mesh) but "
+                     f"{jax.default_backend()} has {avail}")
     if args.serial and (args.deadline_ms is not None
                         or args.queue_cap is not None
                         or args.overload != "reject"):
@@ -357,6 +369,7 @@ def main():
         except ValueError as e:
             ap.error(f"--guarantee: {e}")
 
+    enable_compile_cache()
     pipe, _ = build_pipeline(BuildConfig(
         task=args.task, tiers=tuple(args.tiers.split(",")),
         train_steps_cap=args.train_steps, budget_frac=args.budget_frac,

@@ -190,11 +190,11 @@ def apply_attn(p: Params, x, *, cfg: ModelConfig, sliding: bool, mode: str,
     if mode in ("train", "prefill"):
         o = None
         if _kernel_ok(q.shape[1], 128):
-            from repro.kernels import kernels_enabled
+            from repro.kernels import interpret_mode, kernels_enabled
             if kernels_enabled():
                 from repro.kernels.flash_attention.ops import mha
                 o = mha(q, k, v, causal=cfg.causal, window=window,
-                        bq=128, bk=128)
+                        bq=128, bk=128, interpret=interpret_mode())
         if o is None:
             o = _chunked_attention(q, k, v, causal=cfg.causal, window=window,
                                    q_chunk=q_chunk)
@@ -238,10 +238,11 @@ def apply_attn(p: Params, x, *, cfg: ModelConfig, sliding: bool, mode: str,
         cv = constrain(cv, "dp", seq_axes, "model", None, priority=(0, 2, 1))
         o = None
         if not window and _kernel_ok(s_c, 128):
-            from repro.kernels import kernels_enabled
+            from repro.kernels import interpret_mode, kernels_enabled
             if kernels_enabled():
                 from repro.kernels.decode_attention.ops import gqa_decode
-                o = gqa_decode(q, ck, cv, pos + 1, bk=128)
+                o = gqa_decode(q, ck, cv, pos + 1, bk=128,
+                               interpret=interpret_mode())
         if o is None:
             o = _decode_attention(q, ck, cv, valid_mask=valid)
         new_cache = {"k": ck, "v": cv}

@@ -77,14 +77,14 @@ def apply_moe(p: Params, x, *, cfg: ModelConfig):
     xg = constrain(xg, "dp", "model", None, None)
 
     # --- expert compute ----------------------------------------------------
-    from repro.kernels import kernels_enabled
+    from repro.kernels import interpret_mode, kernels_enabled
     yg = None
     if kernels_enabled() and gated and cfg.ffn_act == "swiglu" \
             and (b * cap) % 8 == 0:
         from repro.kernels.moe_gmm.ops import expert_mlp
         xe = jnp.swapaxes(xg, 0, 1).reshape(e.n_experts, b * cap, d)
         ye = expert_mlp(xe, cast(p["gate"], cfg), cast(p["up"], cfg),
-                        cast(p["down"], cfg))
+                        cast(p["down"], cfg), interpret=interpret_mode())
         yg = jnp.swapaxes(ye.reshape(e.n_experts, b, cap, d), 0, 1)
     if yg is None:
         up = jnp.einsum("becd,edf->becf", xg, cast(p["up"], cfg),

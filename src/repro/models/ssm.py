@@ -160,12 +160,13 @@ def apply_mamba(p: Params, xin, *, cfg: ModelConfig, mode: str, cache=None,
                                        cast(p["conv_bc_b"], cfg)))
         x = xcv.reshape(b, s, n_heads, P)
         Bm, Cm = bcv[..., :N], bcv[..., N:]
-        from repro.kernels import kernels_enabled
+        from repro.kernels import interpret_mode, kernels_enabled
         chunk = min(s_cfg.chunk, s)
         if (use_kernel or kernels_enabled()) and mode == "train" \
                 and s % chunk == 0:
             from repro.kernels.ssd_scan.kernel import ssd_scan
-            y = ssd_scan(x, dt.astype(x.dtype), A, Bm, Cm, chunk=chunk)
+            y = ssd_scan(x, dt.astype(x.dtype), A, Bm, Cm, chunk=chunk,
+                         interpret=interpret_mode())
             state = None  # kernel path is train-only (no state output)
         else:
             y, state = ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)

@@ -5,6 +5,7 @@ Public API (all pure functions of pytrees):
   init_cache(cfg, batch, seq[, dtype])  -> decode cache pytree
   forward_train(params, batch, cfg)     -> (loss, metrics)
   prefill(params, batch, cfg)           -> (last_logits, cache)
+  forward_logits(params, batch, cfg)    -> (B, S, V) logits, uncached
   decode_step(params, cache, tokens, pos, cfg [, mrope_pos]) -> (logits, cache)
 """
 from __future__ import annotations
@@ -414,6 +415,18 @@ def prefill(params, batch, cfg: ModelConfig, max_len: int = 0,
     else:
         logits = unembed(params["embed"], h, cfg)   # per-frame logits
     return logits, new_cache
+
+
+def forward_logits(params, batch, cfg: ModelConfig):
+    """Uncached full-sequence forward -> (B, S, V) logits of every
+    position, at the exact prompt length: no KV cache and no padding.
+    The reference a cached ``prefill`` + ``decode_step`` must match."""
+    x, positions = _embed_inputs(params, batch, cfg, "train")
+    x, _, _ = _apply_stack(params, x, cfg=cfg, mode="train",
+                           positions=positions, cache=None, pos=None,
+                           remat=False)
+    h = apply_norm(params["final_norm"], x, cfg)
+    return unembed(params["embed"], h, cfg)
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig, mrope_pos=None):

@@ -75,7 +75,8 @@ class BuildConfig:
     # supersedes; setting both is an error). mesh_shape=(R, C) lays the
     # local devices out as R rows ("data"/FSDP axis units) x C columns
     # ("model" tensor axis); None = (n_devices, 1), data-parallel only,
-    # which keeps results bit-identical to the unsharded pipeline.
+    # which keeps results bit-identical to the unsharded pipeline on CPU
+    # devices; on a TPU a greedy token may flip at a near-tie.
     shard_tiers: bool = False
     mesh_shape: tuple | None = None
     # pending-set compaction mode for the batch cascade path:

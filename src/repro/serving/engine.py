@@ -189,6 +189,15 @@ class GenerationEngine:
             return seq_bucket < self.cfg.window
         return True
 
+    def bucket_key(self, b: int, s: int, n_new: int) -> tuple[int, int, int]:
+        """(batch, prompt, cache) buckets a (b, s) prompt generating
+        ``n_new`` tokens is compiled and run at."""
+        s_b = bucket_size(s, self.seq_floor)
+        if not self._seq_paddable(s_b):
+            s_b = s
+        return (bucket_size(b, self.batch_floor), s_b,
+                bucket_size(s_b + n_new, self.seq_floor))
+
     def _prefill_fn(self, key: tuple[int, int, int]) -> Callable:
         b_b, s_b, max_len = key
         if key not in self._prefill_fns:
@@ -261,11 +270,7 @@ class GenerationEngine:
         if n_new <= 0:
             return PrefillFuture(self, n_new=0, b=b, b_b=b, s=s,
                                  max_len=0, seed=seed)
-        b_b = bucket_size(b, self.batch_floor)
-        s_b = bucket_size(s, self.seq_floor)
-        if not self._seq_paddable(s_b):
-            s_b = s
-        max_len = bucket_size(s_b + n_new, self.seq_floor)
+        b_b, s_b, max_len = self.bucket_key(b, s, n_new)
 
         toks = np.full((b_b, s_b), self.pad_token, tokens.dtype)
         toks[:b, :s] = tokens

@@ -14,8 +14,11 @@ Each slice is a standard 2-D mesh with axes ``("data", "model")``:
   * "data"  — batch / FSDP axis. Batch-dim sharding splits independent
     rows across devices, and FSDP param sharding all-gathers exact
     weight values before use, so **data-only slices are bit-identical**
-    to the unsharded computation (pinned by tests/test_placement.py's
-    sharded legs).
+    to the unsharded computation on CPU devices (pinned by
+    tests/test_placement.py's sharded legs). Not on a TPU: there the
+    compiler tiles a contraction by the rows each chip holds, and the
+    partitioner may all-reduce partial sums, so a greedy token can flip
+    at a near-tie (``chip_smoke.py --chips 4`` bounds it).
   * "model" — tensor-parallel axis (``sharding.rules`` head/FFN/vocab
     rules). Width defaults to 1 because model-axis matmul reductions
     change float summation order — opt in via ``mesh_shape=(R, C)``

@@ -97,7 +97,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import repro.launch.mesh as M
-M.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+M.make_production_mesh = lambda multi_pod=False: M.auto_mesh(
     (2, 2, 2) if multi_pod else (2, 4),
     ("pod", "data", "model") if multi_pod else ("data", "model"))
 import repro.configs.registry as REG
