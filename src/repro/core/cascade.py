@@ -26,10 +26,12 @@ call sites.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.simulate import MarketData
 
 
@@ -164,22 +166,30 @@ def tier_step(tier: CascadeTier, chunk, j: int, *, scorer: Callable,
     skip the cold ``tier.invoke`` here; scoring, accept, and cost
     charging still run through the identical path below, so speculation
     can only move wall-clock, never answers or charged cost.
+
+    Telemetry (``repro.core.telemetry``): the tier call runs under the
+    ``cascade.invoke`` span and the scorer under ``cascade.score``; the
+    host time after the tier call (casts, scoring, accept) is added to
+    the chunk record open in this context as ``cascade_s``.
     """
-    if prefilled is not None:
-        a, c = _consume_prefilled(tier, chunk, prefilled)
-    else:
-        a, c = tier.invoke(chunk)
+    with telemetry.span(telemetry.INVOKE):
+        if prefilled is not None:
+            a, c = _consume_prefilled(tier, chunk, prefilled)
+        else:
+            a, c = tier.invoke(chunk)
+    t_invoked = time.perf_counter()
     a = np.asarray(a)
     c = np.asarray(c, np.float64)
     if last:
         s = np.full(len(chunk), np.nan)
         accept = np.ones(len(chunk), bool)
     else:
-        if scorer_lock is not None:
-            with scorer_lock:
+        with telemetry.span(telemetry.SCORE):
+            if scorer_lock is not None:
+                with scorer_lock:
+                    raw = scorer(chunk, a, j)
+            else:
                 raw = scorer(chunk, a, j)
-        else:
-            raw = scorer(chunk, a, j)
         s = np.asarray(raw, np.float64)
         accept = None
         if device_masks is not None:
@@ -192,6 +202,9 @@ def tier_step(tier: CascadeTier, chunk, j: int, *, scorer: Callable,
                 accept = np.asarray(mask)
         if accept is None:
             accept = s >= threshold
+    rec = telemetry.current()
+    if rec is not None:
+        rec.cascade_s += time.perf_counter() - t_invoked
     return a, c, s, accept
 
 
@@ -279,15 +292,13 @@ def execute_cascade(tiers: Sequence[CascadeTier], thresholds: Sequence[float],
     resilient = retry is not None or breaker is not None
     health = rmeta = None
     if resilient:
-        import time as _time
-
         from repro.serving.resilience import (TierFault, TierHealth,
                                               invoke_with_retry)
         if clock is None:
-            _t0 = _time.perf_counter()
-            clock = lambda: _time.perf_counter() - _t0  # noqa: E731
+            _t0 = time.perf_counter()
+            clock = lambda: time.perf_counter() - _t0  # noqa: E731
         if sleep is None:
-            sleep = _time.sleep
+            sleep = time.sleep
         # breaker may be a BreakerConfig (fresh breakers for this call)
         # or a live TierHealth shared across calls — a repeatedly-invoked
         # executor then *starts* a pass with tiers already tripped open

@@ -18,6 +18,8 @@ Demo (CPU):
       --devices 4 --on-device-compact     # per-tier device placement
   PYTHONPATH=src python -m repro.launch.serve --requests 200 --stream \\
       --mesh 8,1                          # per-tier mesh slices (sharded)
+  PYTHONPATH=src python -m repro.launch.serve --requests 200 --stream \\
+      --profile /tmp/serve-trace          # profiler trace of the served run
 
 Thin CLI over ``repro.serving.build_pipeline`` — this is the entry point
 a real deployment would point at the production mesh (tiers sharded with
@@ -30,6 +32,7 @@ programs persist in JAX's compilation cache
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import sys
 
@@ -276,6 +279,12 @@ def main():
                          "device (jitted gather+prefix-sum, or the "
                          "Pallas kernel variant); bit-identical to the "
                          "host path")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="capture a jax.profiler trace of the served run "
+                         "into DIR (TensorBoard/XProf or Perfetto): device "
+                         "activity under the serving spans serve.stream, "
+                         "sched.chunk, cascade.*, engine.prefill and "
+                         "engine.decode[.fetch|.dispatch]")
     args = ap.parse_args()
     if args.devices is not None and args.devices < 1:
         ap.error("--devices must be >= 1")
@@ -387,6 +396,27 @@ def main():
         router=RouterConfig(top_lists=10, sample=256)))
 
     test = synthetic.sample(args.task, args.requests, seed=77)
+    profile = contextlib.nullcontext()
+    if args.profile is not None:
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0    # Python call tracing slows the host
+        profile = jax.profiler.trace(args.profile, profiler_options=opts)
+    with profile:
+        res = _serve(pipe, test, args, retry_pol, breaker_cfg)
+    served = res.stopped_at != -2
+    n_served = int(served.sum())
+    acc = (float((res.answers[served] == test.labels[served]).mean())
+           if n_served else float("nan"))
+    avg_cost = float(res.cost[served].mean()) if n_served else 0.0
+    print(res.summary())
+    print(f"accuracy {acc:.3f} over {n_served} served; "
+          f"avg cost ${avg_cost:.6f}/served query "
+          f"({100 * res.savings_frac:.0f}% below top-tier-only)")
+
+
+def _serve(pipe, test, args, retry_pol, breaker_cfg):
+    """One served run of the test set: a stream replay or the batch path."""
     if args.stream:
         arrivals = poisson_arrivals(args.requests, args.rate, seed=77)
         mode = ("serial continuous batcher" if args.serial
@@ -418,15 +448,7 @@ def main():
         res = pipe.serve(test.tokens, clock=vc, sleep=vc.sleep)
     else:
         res = pipe.serve(test.tokens)
-    served = res.stopped_at != -2
-    n_served = int(served.sum())
-    acc = (float((res.answers[served] == test.labels[served]).mean())
-           if n_served else float("nan"))
-    avg_cost = float(res.cost[served].mean()) if n_served else 0.0
-    print(res.summary())
-    print(f"accuracy {acc:.3f} over {n_served} served; "
-          f"avg cost ${avg_cost:.6f}/served query "
-          f"({100 * res.savings_frac:.0f}% below top-tier-only)")
+    return res
 
 
 if __name__ == "__main__":

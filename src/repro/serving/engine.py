@@ -36,6 +36,13 @@ is bit-identical by construction. ``PrefillFuture.cancel`` retires a
 speculation: the cache/token references are dropped so the device
 buffers free, and the pool (``EnginePool.speculate``) untracks it.
 
+Telemetry (``repro.core.telemetry``): ``prefill_async`` runs under the
+``engine.prefill`` span and ``decode_from`` under ``engine.decode``, each
+step split into ``engine.decode.fetch`` (the host blocked on the token)
+and ``engine.decode.dispatch`` (the host enqueueing the next step). Both
+add their host seconds and counts to the chunk record open in the
+caller's context, and nothing when none is.
+
 ``CascadeServer`` is the serving facade over the repo's single cascade
 executor (``repro.core.cascade.execute_cascade``); the full three-strategy
 pipeline (cache + prompt adaptation + cascade) lives in
@@ -50,8 +57,10 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.core import telemetry
 from repro.core.cascade import CascadeTier, execute_cascade
 from repro.models import transformer as T
 
@@ -272,31 +281,39 @@ class GenerationEngine:
                                  max_len=0, seed=seed)
         b_b, s_b, max_len = self.bucket_key(b, s, n_new)
 
-        toks = np.full((b_b, s_b), self.pad_token, tokens.dtype)
-        toks[:b, :s] = tokens
-        toks[b:, :s] = tokens[-1]          # batch filler: replicate a row
+        t0 = time.perf_counter()
+        with telemetry.span(telemetry.PREFILL):
+            toks = np.full((b_b, s_b), self.pad_token, tokens.dtype)
+            toks[:b, :s] = tokens
+            toks[b:, :s] = tokens[-1]      # batch filler: replicate a row
 
-        self.compile_stats["prefill_calls"] += 1
-        fn = self._prefill_fn((b_b, s_b, max_len))
-        if self.mesh is not None:
-            # the across-slice-boundary hop: host-compacted batches are
-            # device_put onto the tier's slice, batch split over "data"
-            from repro.sharding import tier_mesh
-            toks_dev = jax.device_put(
-                toks, tier_mesh.batch_sharding(self.mesh, b_b))
-        else:
-            toks_dev = jnp.asarray(toks)
-        logits, cache = fn(self.params, toks_dev, jnp.int32(s - 1))
-        rkey = jax.random.PRNGKey(seed)
-        last_logits = logits[:, -1]
-        if self.temperature > 0:
-            # the post-prefill token goes through the same keyed
-            # categorical path as every later token — not argmax
-            rkey, sub = jax.random.split(rkey)
-            nxt = jax.random.categorical(sub, last_logits / self.temperature)
-        else:
-            nxt = jnp.argmax(last_logits, -1)
-        nxt = nxt[:, None].astype(jnp.int32)
+            self.compile_stats["prefill_calls"] += 1
+            fn = self._prefill_fn((b_b, s_b, max_len))
+            if self.mesh is not None:
+                # the across-slice-boundary hop: host-compacted batches
+                # are device_put onto the tier's slice, batch split
+                # over "data"
+                from repro.sharding import tier_mesh
+                toks_dev = jax.device_put(
+                    toks, tier_mesh.batch_sharding(self.mesh, b_b))
+            else:
+                toks_dev = jnp.asarray(toks)
+            logits, cache = fn(self.params, toks_dev, jnp.int32(s - 1))
+            rkey = jax.random.PRNGKey(seed)
+            last_logits = logits[:, -1]
+            if self.temperature > 0:
+                # the post-prefill token goes through the same keyed
+                # categorical path as every later token — not argmax
+                rkey, sub = jax.random.split(rkey)
+                nxt = jax.random.categorical(sub,
+                                             last_logits / self.temperature)
+            else:
+                nxt = jnp.argmax(last_logits, -1)
+            nxt = nxt[:, None].astype(jnp.int32)
+        rec = telemetry.current()
+        if rec is not None:
+            rec.prefill_calls += 1
+            rec.prefill_dispatch_s += time.perf_counter() - t0
         return PrefillFuture(self, n_new=n_new, b=b, b_b=b_b, s=s,
                              max_len=max_len, seed=seed, _tok=nxt,
                              _cache=cache, _rkey=rkey)
@@ -321,12 +338,31 @@ class GenerationEngine:
         fut._tok = fut._cache = fut._rkey = None
         fut._retire()
         decode = self._decode_fn(fut.b_b, fut.max_len, cache)
-        out = [np.asarray(nxt)]
-        for i in range(fut.n_new - 1):
-            rkey, sub = jax.random.split(rkey)
-            nxt, cache = decode(self.params, cache, nxt,
-                                jnp.int32(fut.s + i), sub)
-            out.append(np.asarray(nxt))
+        # per step: host blocked on the token (fetch), then host work up
+        # to the next step enqueued (dispatch); two clock reads a step
+        fetch_s = dispatch_s = 0.0
+        with telemetry.span(telemetry.DECODE):
+            t = time.perf_counter()
+            with TraceAnnotation(telemetry.DECODE_FETCH):
+                out = [np.asarray(nxt)]
+            t_host = time.perf_counter()
+            fetch_s += t_host - t
+            for i in range(fut.n_new - 1):
+                with TraceAnnotation(telemetry.DECODE_DISPATCH):
+                    rkey, sub = jax.random.split(rkey)
+                    nxt, cache = decode(self.params, cache, nxt,
+                                        jnp.int32(fut.s + i), sub)
+                t = time.perf_counter()
+                dispatch_s += t - t_host
+                with TraceAnnotation(telemetry.DECODE_FETCH):
+                    out.append(np.asarray(nxt))
+                t_host = time.perf_counter()
+                fetch_s += t_host - t
+        rec = telemetry.current()
+        if rec is not None:
+            rec.decode_steps += fut.n_new - 1
+            rec.decode_dispatch_s += dispatch_s
+            rec.decode_fetch_s += fetch_s
         return np.concatenate(out, axis=1)[:fut.b]
 
     def generate(self, tokens: np.ndarray, n_new: int | None = None,
@@ -506,7 +542,6 @@ class CascadeServer:
     batch_size: int = 256
 
     def serve(self, tokens: np.ndarray) -> dict:
-        t0 = time.time()
         ct = [CascadeTier(t.name, lambda q, t=t: (t.answer(q), t.cost(q)))
               for t in self.tiers]
         res = execute_cascade(ct, self.thresholds,
@@ -517,5 +552,4 @@ class CascadeServer:
             "cost": res["cost"],
             "stopped_at": res["stopped_at"],
             "tier_counts": [c for c in res["tier_counts"]],
-            "latency_s": time.time() - t0,
         }

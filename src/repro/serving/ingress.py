@@ -66,6 +66,7 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.cascade import tier_step
 
 
@@ -187,6 +188,8 @@ class RequestState:
     t_admitted: float | None = None
     t_done: float | None = None
     t_enqueued: float = 0.0         # entered the current tier's wait queue
+    tier_wait: float = 0.0          # summed waits in tier queues, entered ->
+                                    # popped into a chunk, over every tier
     n_chunks: int = 0               # tier chunks this request rode in
     emb: np.ndarray | None = None   # cache-stage embedding (misses only)
     future: asyncio.Future | None = None
@@ -326,6 +329,7 @@ class ContinuousBatcher:
         self.cache_misses = 0
         self.latency = {"embed": 0.0, "cache": 0.0, "cascade": 0.0,
                         "insert": 0.0}
+        self.telemetry = telemetry.StreamTelemetry(m)
 
     _pad_rows = staticmethod(pad_pow2_rows)   # compat alias
 
@@ -335,8 +339,9 @@ class ContinuousBatcher:
         finish immediately, misses enter tier 0's wait queue."""
         if not reqs:
             return
-        hit_mask, cached, emb, embed_s, cache_s = stage1_lookup(
-            self.pipeline, reqs)
+        with telemetry.span(telemetry.ADMIT):
+            hit_mask, cached, emb, embed_s, cache_s = stage1_lookup(
+                self.pipeline, reqs)
         self.latency["embed"] += embed_s
         self.latency["cache"] += cache_s
         self.cache_hits += int(hit_mask.sum())
@@ -399,18 +404,25 @@ class ContinuousBatcher:
         finished by this chunk."""
         q = self._waiting[j]
         batch = [q.popleft() for _ in range(min(self.max_chunk, len(q)))]
+        start = clock()
+        for r in batch:
+            r.tier_wait += start - r.t_enqueued
         toks, b = pad_pow2_rows(np.stack([r.tokens for r in batch]))
         pipe = self.pipeline
         last = j == len(self._tiers) - 1
+        rec = telemetry.ChunkCounters()
         t0 = time.perf_counter()
-        ans, cost, scores, accept = tier_step(
-            self._tiers[j], toks, j, scorer=pipe._pos_scorer,
-            threshold=None if last else pipe.thresholds[j], last=last)
+        with telemetry.counting(rec), telemetry.span(
+                telemetry.CHUNK, tier=j, rows=len(batch)):
+            ans, cost, scores, accept = tier_step(
+                self._tiers[j], toks, j, scorer=pipe._pos_scorer,
+                threshold=None if last else pipe.thresholds[j], last=last)
         ans, cost, scores, accept = ans[:b], cost[:b], scores[:b], accept[:b]
         self.latency["cascade"] += time.perf_counter() - t0
         self.chunks_per_tier[j] += 1
         self._fill.append(len(batch) / self.max_chunk)
         now = clock()
+        self.telemetry.fold(j, rec, batch, start, now)
         finished = []
         for i, r in enumerate(batch):
             r.n_chunks += 1
@@ -440,6 +452,10 @@ class ContinuousBatcher:
         sync/async drivers differ only in how they sleep. Terminates
         when the queue is closed and everything in flight has drained.
         """
+        with telemetry.span(telemetry.STREAM):
+            yield from self._tick_loop(queue, clock)
+
+    def _tick_loop(self, queue: IngressQueue, clock) -> Iterator[float]:
         while True:
             self.admit(queue.due(clock()), clock())
             drain = queue.closed and len(queue) == 0
@@ -476,6 +492,7 @@ class ContinuousBatcher:
         monotonic ``clock`` replaces the wall clock (tests; it must
         eventually pass every arrival offset or the trace never
         drains). Returns the folded ``ServeResult``."""
+        self.telemetry.start()
         t_start = time.perf_counter()
 
         if clock is None:
@@ -496,6 +513,7 @@ class ContinuousBatcher:
         request's future resolves the moment it finishes — until
         ``queue.close()`` lets the loop drain and return the folded
         ``ServeResult``."""
+        self.telemetry.start()
         t_start = time.perf_counter()
         if clock is None:
             def clock() -> float:
@@ -518,6 +536,7 @@ class ContinuousBatcher:
             "chunk_occupancy": float(np.mean(self._fill)) if self._fill
             else 0.0,
             "n_chunks": int(sum(self.chunks_per_tier)),
+            **self.telemetry.publish(done),
         }
 
     def result(self, total_s: float):

@@ -113,10 +113,12 @@ class ServeResult:
     prompt_tokens_saved: int     # adapted vs full prompt, summed over calls
     baseline_cost: float         # top tier + full prompt for every query
     latency: dict                # per-stage seconds
-    # streaming telemetry (stream paths only): per-request latency and
-    # queue-wait arrays, chunks per tier, chunk occupancy; the parallel
-    # scheduler adds per-tier utilization/EWMA estimates, deadline-hit
-    # rate, shed/degraded counts and queue peaks
+    # streaming telemetry (stream paths only): per-request latency,
+    # queue-wait and summed tier-queue-wait arrays, chunks per tier,
+    # chunk occupancy, per-tier host counters and chunk/visit span
+    # records (repro.core.telemetry); the parallel scheduler adds
+    # per-tier utilization/EWMA estimates, deadline-hit rate,
+    # shed/degraded counts and queue peaks
     ingress: dict | None = None
     # contextual-strategy telemetry (pipelines with a ServingStrategy):
     # entry-tier histogram, realized spend rate, predicted-vs-realized
@@ -151,6 +153,14 @@ class ServeResult:
                      f" p95 {np.percentile(rl, 95) * 1e3:.0f}ms over "
                      f"{self.ingress['n_chunks']} chunks (occupancy "
                      f"{self.ingress['chunk_occupancy']:.2f})")
+        if self.ingress is not None and len(self.ingress.get("tier_wait", ())):
+            tw = np.percentile(self.ingress["tier_wait"], 95)
+            extra += f" | tier queue wait p95 {tw * 1e3:.0f}ms"
+            tc = self.ingress["tier_counters"]
+            steps = sum(t["decode_steps"] for t in tc)
+            if steps:
+                host_us = 1e6 * sum(t["decode_dispatch_s"] for t in tc) / steps
+                extra += f" | decode host {host_us:.0f}us/step"
         if self.ingress is not None and "tier_utilization" in self.ingress:
             util = ", ".join(f"{u:.2f}" for u in
                              self.ingress["tier_utilization"])

@@ -106,6 +106,16 @@ when the pipeline exposes a pool). With no resilience dials the
 TierFault path is structurally unreachable and the scheduler is
 bit-identical to the pre-resilience one (the zero-fault legs of
 tests/test_placement.py).
+
+**Telemetry** (``repro.core.telemetry``): the served window runs under
+the ``serve.stream`` span, each admission under ``sched.admit`` and each
+chunk under ``sched.chunk``. Each chunk's worker opens a counter record
+that the engine and ``tier_step`` add their host time to; the record is
+folded into its tier's totals under the lock, with a chunk span record
+and one visit record per request. ``stats()`` publishes them with each
+answered request's summed tier-queue wait (``tier_wait``, entering a
+tier's queue to its chunk being popped); the serial batcher publishes
+the same keys.
 """
 from __future__ import annotations
 
@@ -117,6 +127,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.cascade import CascadeTier, tier_step
 from repro.serving.ingress import (IngressQueue, RequestState,
                                    fold_stream_result, pad_pow2_rows,
@@ -215,6 +226,9 @@ class TierScheduler:
                         "insert": 0.0}
         if self._assign:
             self.latency["assign"] = 0.0
+        # per-tier host counters and chunk/visit span records
+        # (repro.core.telemetry), folded under _mu
+        self.telemetry = telemetry.StreamTelemetry(m)
 
         # speculation state (all under _mu; see module docstring).
         # _decoding[j]: rid -> request for rows inside tier j's running
@@ -269,12 +283,13 @@ class TierScheduler:
         strat = self._strategy
         routed = (strat is not None and not self._assign
                   and getattr(strat, "router", None) is not None)
-        hit_mask, cached, emb, embed_s, cache_s = stage1_lookup(
-            self.pipeline, reqs, cache_lock=self._cache_mu,
-            need_emb=routed or self._assign)
-        entries = probs = None
-        if routed:
-            entries, probs = strat.route(emb)
+        with telemetry.span(telemetry.ADMIT):
+            hit_mask, cached, emb, embed_s, cache_s = stage1_lookup(
+                self.pipeline, reqs, cache_lock=self._cache_mu,
+                need_emb=routed or self._assign)
+            entries = probs = None
+            if routed:
+                entries, probs = strat.route(emb)
         m = len(self._tiers)
         keep_emb = self.pipeline.cache is not None
         with self._cv:
@@ -554,6 +569,7 @@ class TierScheduler:
                  for _ in range(min(self._effective_chunk(), len(q)))]
         for r in batch:
             self.estimators[j].observe_wait(now - r.t_enqueued)
+            r.tier_wait += now - r.t_enqueued
         self._busy[j] += len(batch)
         if self.slo.speculate:
             # expose the chunk as downstream speculation candidates for
@@ -614,14 +630,18 @@ class TierScheduler:
         the one-invoke-at-a-time backend contract holds. Rows that were
         accepted upstream while we were invoking are cancelled here."""
         toks, b = pad_pow2_rows(np.stack([r.tokens for r in rows]))
+        rec = telemetry.ChunkCounters()
         t0 = time.perf_counter()
         try:
-            a, c = self._tiers[t].invoke(toks)
+            with telemetry.counting(rec), telemetry.span(
+                    telemetry.CHUNK, tier=t, rows=len(rows), speculative=1):
+                a, c = self._tiers[t].invoke(toks)
         except TierFault:
             # speculation is opportunistic — no retries, just release
             # the rows (they stay eligible for the real escalation
             # path) and feed the breaker its free failure signal
             with self._cv:
+                self.telemetry.fold(t, rec)
                 self.spec_aborted += len(rows)
                 self.spec_issued -= len(rows)
                 for r in rows:
@@ -638,6 +658,7 @@ class TierScheduler:
         c = np.asarray(c, np.float64)[:b]
         row_s = spent / len(rows)
         with self._cv:
+            self.telemetry.fold(t, rec)
             self.spec_busy_s[t] += spent
             self.spec_chunks[t] += 1
             for i, r in enumerate(rows):
@@ -772,7 +793,8 @@ class TierScheduler:
         self._finish_locked(r, now)
 
     def _failover_chunk(self, j: int, batch: list[RequestState],
-                        prefilled, meta: dict):
+                        prefilled, meta: dict,
+                        rec: telemetry.ChunkCounters):
         """Tier j failed this chunk even after retries: escalate the
         rows forward — the cascade structure IS the failover path — or,
         at the last tier, resolve each row from its recorded fallback
@@ -782,6 +804,7 @@ class TierScheduler:
         last = j == len(self._tiers) - 1
         now = clock()
         with self._cv:
+            self.telemetry.fold(j, rec)     # the work done, not a chunk
             self.retry_count += meta["retries"]
             self.retry_backoff_s += meta["backoff"]
             self.failover_count += len(batch)
@@ -856,16 +879,20 @@ class TierScheduler:
         meta = {"retries": 0, "backoff": 0.0}
         tier = (self._resilient_tier(j, self._batch_deadline(batch), meta)
                 if self._resilient else self._tiers[j])
+        rec = telemetry.ChunkCounters()
+        start = clock()
         t0 = time.perf_counter()
         try:
-            ans, cost, scores, accept = tier_step(
-                tier, toks, j, scorer=pipe._pos_scorer,
-                threshold=None if last else thresholds[j], last=last,
-                scorer_lock=self._scorer_mu, prefilled=prefilled)
+            with telemetry.counting(rec), telemetry.span(
+                    telemetry.CHUNK, tier=j, rows=len(batch)):
+                ans, cost, scores, accept = tier_step(
+                    tier, toks, j, scorer=pipe._pos_scorer,
+                    threshold=None if last else thresholds[j], last=last,
+                    scorer_lock=self._scorer_mu, prefilled=prefilled)
         except TierFault:
             if not self._resilient:     # no resilience layer: fatal, as
                 raise                   # any tier exception always was
-            self._failover_chunk(j, batch, prefilled, meta)
+            self._failover_chunk(j, batch, prefilled, meta, rec)
             return
         ans, cost, scores, accept = (ans[:b], cost[:b], scores[:b],
                                      accept[:b])
@@ -915,6 +942,8 @@ class TierScheduler:
                 r.emb = None
         m = len(self._tiers)
         with self._cv:
+            # before the escalations below move t_enqueued on
+            self.telemetry.fold(j, rec, batch, start, now)
             self.retry_count += meta["retries"]
             self.retry_backoff_s += meta["backoff"]
             self.estimators[j].observe_chunk(chunk_s, len(batch))
@@ -1037,6 +1066,7 @@ class TierScheduler:
         request's future resolves the moment it finishes — until
         ``queue.close()`` lets the stream drain. Returns the folded
         ``ServeResult``."""
+        self.telemetry.start()
         t_start = time.perf_counter()
         if clock is None:
             def clock() -> float:
@@ -1048,32 +1078,39 @@ class TierScheduler:
             self._sleep = lambda _s: None
         self._start(clock)
         try:
-            while True:
-                now = clock()
-                self._admit(queue.due(now), now)
-                drained = queue.closed and len(queue) == 0
-                if self._win_buf is not None:
-                    # window formation: drain on fill/age/deadline
-                    # pressure — or force-flush a partial window once
-                    # no further arrival can ever top it up
-                    self._drain_window(now, force=drained)
-                with self._cv:
-                    self._ingress_drained = drained
-                    if self._error is not None:
-                        break
-                    if drained and self._inflight == 0:
-                        break
-                    self._cv.notify_all()
-                nxt = queue.next_arrival()
-                pause = (self.IDLE_POLL if nxt is None else
-                         min(max(nxt - clock(), 0.0), self.IDLE_POLL))
-                # always yield so producers run, even at pause=0
-                await asyncio.sleep(pause)
+            with telemetry.span(telemetry.STREAM):
+                await self._drive(queue, clock)
         finally:
             self._shutdown()
         if self._error is not None:
             raise self._error
         return self.result(clock())
+
+    async def _drive(self, queue: IngressQueue, clock):
+        """The admission loop: admit what is due, hand drain state to the
+        workers, and return once the stream has drained (or a worker
+        died)."""
+        while True:
+            now = clock()
+            self._admit(queue.due(now), now)
+            drained = queue.closed and len(queue) == 0
+            if self._win_buf is not None:
+                # window formation: drain on fill/age/deadline
+                # pressure — or force-flush a partial window once
+                # no further arrival can ever top it up
+                self._drain_window(now, force=drained)
+            with self._cv:
+                self._ingress_drained = drained
+                if self._error is not None:
+                    return
+                if drained and self._inflight == 0:
+                    return
+                self._cv.notify_all()
+            nxt = queue.next_arrival()
+            pause = (self.IDLE_POLL if nxt is None else
+                     min(max(nxt - clock(), 0.0), self.IDLE_POLL))
+            # always yield so producers run, even at pause=0
+            await asyncio.sleep(pause)
 
     def run_trace(self, tokens: np.ndarray,
                   arrivals: Sequence[float] | None = None, *,
@@ -1105,6 +1142,9 @@ class TierScheduler:
             "chunk_occupancy": float(np.mean(self._fill)) if self._fill
             else 0.0,
             "n_chunks": int(sum(self.chunks_per_tier)),
+            # per-tier queue waits, host counters and span records
+            # (repro.core.telemetry), the same keys as the serial batcher
+            **self.telemetry.publish(served),
             # scheduler extensions
             "tier_utilization": [e.utilization(total_s)
                                  for e in self.estimators],
