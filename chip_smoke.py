@@ -18,7 +18,10 @@ starts no other. Phases, one printed line each:
                forward pass.
   4. kernels   the tier-0 weights with ``enable_kernels(True)``: the
                compiled prefill and decode hold Mosaic kernels
-               (``tpu_custom_call``) and their logits match the jnp path.
+               (``tpu_custom_call``) and their logits match the served
+               path's, whose decode is jnp. On a TPU the served prefill
+               takes the flash kernel without the switch, so phase 3
+               already checks it against the reference.
 
 With ``--chips 4`` only phase 5 runs: the phase-3 cascade with its tiers
 pinned to chips of their own (``plan_placement``) and sliced over a 4x1
@@ -334,7 +337,8 @@ def phase_kernels(cfg, params, engine, prompts, ref_head) -> dict:
 
     Runs phase 3's prompts (padded to a 256-token bucket, which admits
     the flash kernel) through the engine's prefill and one decode step,
-    with the kernels and on ``engine``'s jnp path. ``ref_head`` holds the
+    with the kernels and on ``engine``'s served path (jnp decode; on a
+    TPU its prefill takes the flash kernel without the switch). ``ref_head`` holds the
     uncached float32 logits of the first prompts at the same two
     positions (phase 3's reference). Both paths compute in ``cfg.dtype``
     and each lands some distance from float32; two paths as accurate as

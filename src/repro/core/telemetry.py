@@ -67,6 +67,8 @@ class ChunkCounters:
     """Host work of one chunk, added to by the layers that do it."""
 
     prefill_calls: int = 0
+    prefill_flash_calls: int = 0        # of them, attention in the flash
+                                        # kernel
     prefill_dispatch_s: float = 0.0     # host padding + dispatch
     decode_steps: int = 0
     decode_dispatch_s: float = 0.0      # host from a token to the next step

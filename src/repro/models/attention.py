@@ -1,11 +1,25 @@
 """Attention mixers: GQA (full / sliding-window / encoder) and DeepSeek MLA.
 
-Full-sequence attention is *query-chunked* (flash-style running softmax is
-in the Pallas kernel; here we chunk queries so the (q, S) score block stays
-bounded — mathematically identical to full softmax). Decode attends one
-token against a KV cache; sliding-window layers keep a ring-buffer cache.
+Which path computes full-sequence GQA attention:
+
+* served prefill on a TPU, in a program on one device, at a length the
+  kernel tiles (``flash_prefill``): the blocked flash kernel
+  (``repro.kernels.flash_attention``), which visits only the key blocks
+  inside each query block's causal/window band;
+* everywhere else (train mode, which needs a VJP the kernel lacks; the
+  CPU backend, the oracle; lengths that do not tile; programs GSPMD
+  partitions over a mesh; MLA): ``_chunked_attention``, which chunks
+  queries so the (q, S) score block stays bounded — mathematically
+  identical to full softmax. ``enable_kernels(True)`` also routes train
+  mode, and prefill off the TPU, through the kernel where S tiles.
+
+Decode attends one token against a KV cache; sliding-window layers keep a
+ring-buffer cache.
 """
 from __future__ import annotations
+
+import contextlib
+import contextvars
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +34,34 @@ NEG_INF = -1e30
 
 def _kernel_ok(seq: int, block: int) -> bool:
     return seq % block == 0
+
+
+#: set while tracing a program that GSPMD partitions over a mesh: Mosaic
+#: kernels cannot be partitioned automatically
+_partitioned: contextvars.ContextVar[bool] = contextvars.ContextVar(
+    "repro_partitioned_trace", default=False)
+
+
+@contextlib.contextmanager
+def partitioned(on: bool = True):
+    """Trace the block's programs as partitioned over a mesh (``on``)."""
+    token = _partitioned.set(on)
+    try:
+        yield
+    finally:
+        _partitioned.reset(token)
+
+
+def flash_prefill(cfg: ModelConfig, seq: int) -> bool:
+    """Whether prefill attention over ``seq`` tokens runs through the
+    flash kernel: GQA attention, on a TPU backend, in a program on one
+    device, at a length the kernel tiles. The engine counts its kernel
+    prefills by this same test."""
+    if cfg.mla is not None or jax.default_backend() != "tpu" \
+            or _partitioned.get():
+        return False
+    from repro.kernels.flash_attention.ops import prefill_blocks
+    return prefill_blocks(seq) is not None
 
 
 # ---------------------------------------------------------------------------
@@ -188,14 +230,13 @@ def apply_attn(p: Params, x, *, cfg: ModelConfig, sliding: bool, mode: str,
     window = cfg.window if sliding else 0
 
     if mode in ("train", "prefill"):
-        o = None
-        if _kernel_ok(q.shape[1], 128):
-            from repro.kernels import interpret_mode, kernels_enabled
-            if kernels_enabled():
-                from repro.kernels.flash_attention.ops import mha
-                o = mha(q, k, v, causal=cfg.causal, window=window,
-                        bq=128, bk=128, interpret=interpret_mode())
-        if o is None:
+        from repro.kernels import interpret_mode, kernels_enabled
+        if (mode == "prefill" and flash_prefill(cfg, s)) or (
+                kernels_enabled() and _kernel_ok(s, 128)):
+            from repro.kernels.flash_attention.ops import mha
+            o = mha(q, k, v, causal=cfg.causal, window=window,
+                    interpret=interpret_mode())
+        else:
             o = _chunked_attention(q, k, v, causal=cfg.causal, window=window,
                                    q_chunk=q_chunk)
         new_cache = None

@@ -417,6 +417,14 @@ def prefill(params, batch, cfg: ModelConfig, max_len: int = 0,
     return logits, new_cache
 
 
+def prefill_flash(cfg: ModelConfig, seq: int) -> bool:
+    """Whether ``prefill`` over ``seq`` tokens computes attention in the
+    flash kernel (``attention.flash_prefill``); an encoder's prefill runs
+    in train mode and never does."""
+    return (cfg.causal and cfg.has_attention
+            and attention.flash_prefill(cfg, seq))
+
+
 def forward_logits(params, batch, cfg: ModelConfig):
     """Uncached full-sequence forward -> (B, S, V) logits of every
     position, at the exact prompt length: no KV cache and no padding.

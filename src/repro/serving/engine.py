@@ -41,7 +41,9 @@ Telemetry (``repro.core.telemetry``): ``prefill_async`` runs under the
 step split into ``engine.decode.fetch`` (the host blocked on the token)
 and ``engine.decode.dispatch`` (the host enqueueing the next step). Both
 add their host seconds and counts to the chunk record open in the
-caller's context, and nothing when none is.
+caller's context, and nothing when none is; a prefill whose attention
+runs in the flash kernel (``transformer.prefill_flash``, decided per
+bucket) also counts as a ``prefill_flash_calls``.
 
 ``CascadeServer`` is the serving facade over the repo's single cascade
 executor (``repro.core.cascade.execute_cascade``); the full three-strategy
@@ -62,6 +64,7 @@ from jax.profiler import TraceAnnotation
 from repro.configs.base import ModelConfig
 from repro.core import telemetry
 from repro.core.cascade import CascadeTier, execute_cascade
+from repro.models import attention
 from repro.models import transformer as T
 
 
@@ -164,6 +167,9 @@ class GenerationEngine:
         if self.device is not None:
             self.params = jax.device_put(self.params, self.device)
         self._prefill_fns: dict[tuple[int, int, int], Callable] = {}
+        # per bucket: whether its prefill's attention runs in the flash
+        # kernel, by the test the model applies while tracing it
+        self._prefill_flash: dict[tuple[int, int, int], bool] = {}
         self.compile_stats = {"prefill_compiles": 0, "prefill_calls": 0}
 
         def _decode_body(params, cache, tok, pos, key):
@@ -211,10 +217,17 @@ class GenerationEngine:
         b_b, s_b, max_len = key
         if key not in self._prefill_fns:
             self.compile_stats["prefill_compiles"] += 1
+            # GSPMD partitions a mesh-sharded prefill, and cannot
+            # partition a Mosaic kernel: it keeps the jnp attention
+            sharded = self.mesh is not None
 
             def fn(p, toks, last):
-                return T.prefill(p, {"tokens": toks}, self.cfg,
-                                 max_len=max_len, last_index=last)
+                with attention.partitioned(sharded):
+                    return T.prefill(p, {"tokens": toks}, self.cfg,
+                                     max_len=max_len, last_index=last)
+
+            with attention.partitioned(sharded):
+                self._prefill_flash[key] = T.prefill_flash(self.cfg, s_b)
 
             if self.mesh is None:
                 self._prefill_fns[key] = jax.jit(fn)
@@ -279,7 +292,8 @@ class GenerationEngine:
         if n_new <= 0:
             return PrefillFuture(self, n_new=0, b=b, b_b=b, s=s,
                                  max_len=0, seed=seed)
-        b_b, s_b, max_len = self.bucket_key(b, s, n_new)
+        key = self.bucket_key(b, s, n_new)
+        b_b, s_b, max_len = key
 
         t0 = time.perf_counter()
         with telemetry.span(telemetry.PREFILL):
@@ -288,7 +302,7 @@ class GenerationEngine:
             toks[b:, :s] = tokens[-1]      # batch filler: replicate a row
 
             self.compile_stats["prefill_calls"] += 1
-            fn = self._prefill_fn((b_b, s_b, max_len))
+            fn = self._prefill_fn(key)
             if self.mesh is not None:
                 # the across-slice-boundary hop: host-compacted batches
                 # are device_put onto the tier's slice, batch split
@@ -313,6 +327,7 @@ class GenerationEngine:
         rec = telemetry.current()
         if rec is not None:
             rec.prefill_calls += 1
+            rec.prefill_flash_calls += int(self._prefill_flash[key])
             rec.prefill_dispatch_s += time.perf_counter() - t0
         return PrefillFuture(self, n_new=n_new, b=b, b_b=b_b, s=s,
                              max_len=max_len, seed=seed, _tok=nxt,

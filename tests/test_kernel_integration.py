@@ -70,3 +70,92 @@ def test_decode_matches_reference():
     enable_kernels(False)
     err = float(jnp.abs(lg_ref - lg_k).max() / (jnp.abs(lg_ref).max() + 1e-9))
     assert err < 1e-3, err
+
+
+# ---------------------------------------------------------------------------
+# Served prefill: the flash kernel on a TPU backend, routed by observable
+# input (mode, backend, length, partitioning) with no switch
+# ---------------------------------------------------------------------------
+
+
+def _cfg_windowed():
+    return dataclasses.replace(
+        _cfg_dense(), name="ki-windowed", n_heads=8, n_kv_heads=2,
+        period=(LayerSpec("attn_sliding", "dense"),), window=64)
+
+
+def _as_tpu(monkeypatch):
+    """The backend check sees a TPU; kernels still run interpreted."""
+    import repro.kernels as K
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(K, "interpret_mode", lambda: True)
+
+
+ROUTES = {  # case: (backend, mode, seq, partitioned, kernel expected)
+    "tpu-prefill": ("tpu", "prefill", 256, False, True),
+    "tpu-train": ("tpu", "train", 256, False, False),
+    "tpu-untiled-length": ("tpu", "prefill", 96, False, False),
+    "cpu-prefill": ("cpu", "prefill", 256, False, False),
+    "tpu-partitioned": ("tpu", "prefill", 256, True, False),
+}
+
+
+@pytest.mark.parametrize("case", ROUTES)
+def test_prefill_attention_route(monkeypatch, case):
+    """Prefill on a TPU takes the kernel where S tiles, and matches
+    ``_chunked_attention``; train mode, the CPU, a length that does not
+    tile and a partitioned program keep ``_chunked_attention``. The
+    engine's test (``prefill_flash``) agrees with the route taken."""
+    from repro.models import attention
+
+    backend, mode, seq, sharded, kernel = ROUTES[case]
+    cfg = _cfg_windowed()
+    p = attention.init_attn(KEY, cfg)
+    x = jax.random.normal(KEY, (2, seq, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(seq), (2, seq))
+
+    def run(x):
+        return attention.apply_attn(p, x, cfg=cfg, sliding=True, mode=mode,
+                                    positions=pos, max_len=seq)[0]
+
+    want = run(x)                       # the CPU: _chunked_attention
+    if backend == "tpu":
+        _as_tpu(monkeypatch)
+    with attention.partitioned(sharded):
+        routed = "pallas_call" in str(jax.make_jaxpr(run)(x))
+        got = run(x)
+        assert T.prefill_flash(cfg, seq) == (kernel or mode == "train")
+    assert routed == kernel
+    assert jnp.allclose(got, want, atol=1e-5, rtol=1e-4), \
+        float(jnp.abs(got - want).max())
+
+
+def test_prefill_flash_calls_counts_kernel_prefills(monkeypatch):
+    """A prefill whose bucket tiles counts as a kernel prefill and its
+    program holds the kernel; a short bucket and a mesh-sharded engine
+    count none and hold none."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core import telemetry
+    from repro.serving.engine import GenerationEngine
+
+    cfg = _cfg_dense()
+    params = T.init_params(KEY, cfg)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    _as_tpu(monkeypatch)
+    single = GenerationEngine(cfg, params, max_new_tokens=2)
+    sharded = GenerationEngine(cfg, params, max_new_tokens=2, mesh=mesh)
+    for eng, want in ((single, (3, 2)), (sharded, (3, 0))):
+        rec = telemetry.ChunkCounters()
+        with telemetry.counting(rec):
+            for width in (128, 20, 128):    # buckets 128, 32, 128
+                eng.generate(np.ones((2, width), np.int32), 2)
+        assert (rec.prefill_calls, rec.prefill_flash_calls) == want
+        for key, flash in eng._prefill_flash.items():
+            toks = jax.ShapeDtypeStruct(key[:2], jnp.int32)
+            jaxpr = jax.make_jaxpr(eng._prefill_fn(key))(
+                eng.params, toks, jnp.int32(0))
+            assert ("pallas_call" in str(jaxpr)) == flash == (
+                eng is single and key[1] == 128)

@@ -8,6 +8,7 @@ from repro.kernels.cascade_compact.ops import compact
 from repro.kernels.cascade_compact.ref import compact_ref
 from repro.kernels.decode_attention.ops import gqa_decode
 from repro.kernels.decode_attention.ref import decode_ref
+from repro.kernels.flash_attention.kernel import band_steps, kv_band
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.moe_gmm.kernel import gmm
@@ -28,9 +29,13 @@ def _rand(shape, dtype=jnp.float32, key=KEY):
     (2, 256, 4, 2, 64),     # GQA 2:1
     (1, 256, 8, 1, 32),     # MQA
     (2, 128, 4, 4, 128),    # MXU-width head dim
+    (1, 512, 4, 2, 64),     # S > window: whole kv blocks skipped
+    (1, 256, 12, 1, 128),   # MQA at StarCoder2's ratio
+    (1, 256, 4, 1, 256),    # gemma3's head dim
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0),
+                                           (True, 128)])
 def test_flash_attention_sweep(b, s, h, kvh, d, dtype, causal, window):
     q = _rand((b, s, h, d), dtype)
     k = _rand((b, s, kvh, d), dtype, jax.random.PRNGKey(1))
@@ -46,6 +51,34 @@ def test_flash_attention_sweep(b, s, h, kvh, d, dtype, causal, window):
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert jnp.allclose(o.astype(jnp.float32), r.astype(jnp.float32),
                         atol=tol, rtol=tol), float(jnp.abs(o - r).max())
+
+
+@pytest.mark.parametrize("seq,bq,bk", [(512, 64, 64), (512, 128, 64),
+                                        (512, 64, 128), (384, 128, 128)])
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 128),
+                                           (True, 100), (False, 0),
+                                           (False, 64)])
+def test_kv_band_visits_exactly_the_blocks_with_a_visible_key(seq, bq, bk,
+                                                              causal,
+                                                              window):
+    """Every kv block the kernel visits for a query block holds a key one
+    of its queries may attend, and every block that holds one is
+    visited; the grid's kv length is the widest band."""
+    qpos, kpos = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    mask = np.ones((seq, seq), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    band = dict(bq=bq, bk=bk, seq=seq, causal=causal, window=window)
+    widths = []
+    for i in range(seq // bq):
+        first, last = kv_band(i, **band)
+        blocks = mask[i * bq:(i + 1) * bq].reshape(bq, seq // bk, bk)
+        seen = np.flatnonzero(blocks.any(axis=(0, 2)))
+        assert list(seen) == list(range(int(first), int(last) + 1)), i
+        widths.append(int(last) - int(first) + 1)
+    assert band_steps(**band) == max(widths)
 
 
 @pytest.mark.parametrize("b,s,h,kvh,d,length", [

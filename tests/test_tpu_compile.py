@@ -1,7 +1,8 @@
 """Compiles for a described TPU v5e chip: the serving path's Pallas
 kernels and one full-width gemma3-1b decode step, at gemma3-1b's
-published widths. Nothing runs; the chip's compiler (Mosaic for the
-kernels) refuses here what it would refuse on the chip.
+published widths, and the flash prefill kernel at the longctx bucket.
+Nothing runs; the chip's compiler (Mosaic for the kernels) refuses here
+what it would refuse on the chip.
 
 The topology is described inside a fixture and nowhere else: only one
 process at a time may load the TPU library, and pytest's workers each
@@ -53,6 +54,20 @@ def test_flash_prefill_kernel_compiles(one_chip):
     kv = _spec(one_chip, (b, s, CFG.n_kv_heads, hd))
     c = _compile(lambda q, k, v: mha(q, k, v, causal=True, window=CFG.window,
                                      bq=128, bk=128, interpret=False),
+                 q, kv, kv)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_flash_prefill_kernel_compiles_at_the_longctx_bucket(one_chip):
+    """The served prefill's kernel at StarCoder2's one-chip share and the
+    longctx bucket: 8 x 6144 tokens, 12 query heads on one KV head,
+    head_dim 128, window 4096, blocks chosen from the shape."""
+    from repro.kernels.flash_attention.ops import mha
+
+    b, s, hd = 8, 6144, 128
+    q = _spec(one_chip, (b, s, 12, hd))
+    kv = _spec(one_chip, (b, s, 1, hd))
+    c = _compile(lambda q, k, v: mha(q, k, v, causal=True, window=4096),
                  q, kv, kv)
     assert "tpu_custom_call" in c.as_text()
 
